@@ -52,7 +52,7 @@ batches = agent_batches(cfg.vocab_size, A, 2, 64, seed=0)
 
 toks, targs = next(batches)
 batch = {"tokens": jnp.asarray(toks), "targets": jnp.asarray(targs)}
-with mesh:
+with jax.set_mesh(mesh):
     t0 = time.monotonic()
     state, m = step_fn(state, batch, jnp.int32(0))
     jax.block_until_ready(m["loss"])

@@ -61,7 +61,7 @@ step_fn = jax.jit(make_train_step(model, tcfg), donate_argnums=(0,))
 batches = agent_batches(cfg.vocab_size, a, bpa, seq, seed=0)
 
 losses = []
-with mesh:
+with jax.set_mesh(mesh):
     for step in range(steps):
         toks, targs = next(batches)
         batch = {"tokens": jnp.asarray(toks), "targets": jnp.asarray(targs)}
@@ -81,7 +81,7 @@ if args.baseline:
     opt_state = opt.init(params)
     bstep = jax.jit(make_dp_baseline_step(model, opt, constant(3e-4)))
     batches = agent_batches(cfg.vocab_size, a, bpa, seq, seed=0)
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(steps):
             toks, targs = next(batches)
             batch = {"tokens": jnp.asarray(toks.reshape(-1, seq)),

@@ -81,15 +81,20 @@ def param_shardings(mesh, shapes, leading_axis="agent", axes=None):
     """
     if axes is None:
         axes = _mesh_axes(mesh, ("replica", "model"))
-    skip = 1 if leading_axis else 0
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, stacked_spec(s.shape, axes,
+                                                   leading_axis)),
+        shapes)
 
-    def one(s):
-        entries = list(greedy_spec(s.shape, axes, skip_leading=skip))
-        if leading_axis and entries:
-            entries[0] = leading_axis
-        return NamedSharding(mesh, P(*entries))
 
-    return jax.tree.map(one, shapes)
+def stacked_spec(shape, axes, leading_axis="agent") -> P:
+    """PartitionSpec of one leaf: `leading_axis` on dim 0 (None for an
+    unstacked leaf), `greedy_spec` over `axes` for the rest."""
+    entries = list(greedy_spec(shape, axes,
+                               skip_leading=1 if leading_axis else 0))
+    if leading_axis and entries:
+        entries[0] = leading_axis
+    return P(*entries)
 
 
 def state_shardings(mesh, state_shapes):

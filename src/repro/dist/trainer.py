@@ -13,9 +13,11 @@ Theorems 2/3 analyze, as one SPMD program over the ("agent", "replica",
     the fused Pallas kernel in `repro.kernels.prox_update` (one VMEM pass
     produces both x_new and the token credit delta (x_new - x)/A,
     eq. 12b),
-  * tokens then move one hop on the agent ring via `jax.lax.ppermute`
-    (expressed under `jax.vmap(axis_name="agent")`, so the same program
-    runs unsharded on one host or sharded over the mesh agent axis).
+  * tokens then move one hop on the agent ring via `jax.lax.ppermute`:
+    under `jax.vmap(axis_name="agent")` with no mesh (all agents on one
+    device), and per device under `jax.shard_map` when a mesh is set
+    (`jax.set_mesh`), where it is a collective-permute between chips.
+    The kernel runs per device shard the same way.
 
 Paper-faithful mode (`accumulate_between_visits=False`) leaves the
 A - M non-holding agents bit-untouched — the invariant
@@ -25,10 +27,51 @@ at the next activation, so no batch is wasted on idle agents.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ops import prox_update_tree
+from repro.dist.sharding import stacked_spec
+from repro.kernels.ops import prox_update
+
+
+def _mesh_axes():
+    """{axis: size} of the mesh set by `jax.set_mesh`, or None when
+    there is no mesh (or one device): the agents are then vmapped."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return None
+    return dict(mesh.shape)
+
+
+def _on_shards(fn, axes, *leaves):
+    """Run `fn` on each device's own shard of agent-stacked `leaves`, in
+    the layout `state_shardings` gives them (the mesh's agent axis holds
+    one agent per index).  Used where GSPMD would move whole stacks: a
+    Pallas call is opaque to it (every device would gather every
+    agent's operands) and a vmapped ppermute lowers to a gather (every
+    device would receive every agent's token)."""
+    spec = stacked_spec(leaves[0].shape,
+                        {a: axes[a] for a in ("replica", "model")
+                         if a in axes})
+    # check_vma=False: a Pallas call declares no varying mesh axes for its
+    # outputs (and the CPU interpreter would trip over the check)
+    return jax.shard_map(fn, in_specs=(spec,) * len(leaves),
+                         out_specs=spec, check_vma=False)(*leaves)
+
+
+def _prox_update_tree(params, g_eff, zsum, **kw):
+    """The fused kernel over agent-stacked trees -> (x_full, d_full).
+    It is elementwise, so running it per shard is exact."""
+    axes = _mesh_axes()
+    local = lambda x, g, z: prox_update(x, g, z, **kw)
+    if axes is not None:
+        local = functools.partial(_on_shards, local, axes)
+    pairs = jax.tree.map(local, params, g_eff, zsum)
+    is_pair = lambda p: isinstance(p, tuple)
+    return (jax.tree.map(lambda p: p[0], pairs, is_leaf=is_pair),
+            jax.tree.map(lambda p: p[1], pairs, is_leaf=is_pair))
 
 
 def _broadcast(mask, leaf):
@@ -90,8 +133,12 @@ def make_train_step(model, tcfg):
 
     def ring_shift(leaf):
         # one hop on the agent ring: slot i receives slot i-1's token
-        return jax.vmap(lambda t: jax.lax.ppermute(t, "agent", perm),
-                        axis_name="agent")(leaf)
+        hop = lambda t: jax.lax.ppermute(t, "agent", perm)
+        axes = _mesh_axes()
+        if axes is None:
+            return jax.vmap(hop, axis_name="agent")(leaf)
+        assert axes["agent"] == a, (axes, a)
+        return _on_shards(hop, axes, leaf)
 
     def step_fn(state, batch, step):
         params, token = state["params"], state["token"]
@@ -116,7 +163,7 @@ def make_train_step(model, tcfg):
         zsum = jax.tree.map(lambda z: z.sum(axis=1), zhat)
 
         # fused closed-form update (eq. 15) + token credit (eq. 12b)
-        x_full, d_full = prox_update_tree(
+        x_full, d_full = _prox_update_tree(
             params, g_eff, zsum, tau=tau, rho=rho, num_walks=m,
             num_agents=a)
 

@@ -1,8 +1,9 @@
 """Jit-ready wrappers around the Pallas kernels.
 
 Shape plumbing between model layouts ([B,S,H,hd] etc.) and kernel layouts
-([BH,S,hd] etc.), plus automatic interpret mode on non-TPU backends so the
-whole suite runs (and is tested) on CPU.
+([BH,S,hd] etc.).  Kernels run compiled on TPU and in interpret mode on
+CPU (so the whole suite runs, and is tested, there); any other backend
+is refused rather than silently interpreted.
 """
 from __future__ import annotations
 
@@ -21,13 +22,20 @@ from repro.kernels.rwkv6_scan import rwkv6_scan_bh
 
 
 def _interpret_default(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    if interpret is not None:
+        return interpret
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise NotImplementedError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"backend {platform!r} is neither")
 
 
 # ---------------------------------------------------------------------------
-# prox update over pytrees
+# prox update
 # ---------------------------------------------------------------------------
 
 
@@ -56,22 +64,6 @@ def prox_update(x, g, zsum, *, tau, rho, num_walks, num_agents,
             flat = flat[:n]
         return flat.reshape(shape).astype(dtype)
     return untile(x_new, x.dtype), untile(delta, jnp.float32)
-
-
-def prox_update_tree(xs, gs, zsums, *, tau, rho, num_walks, num_agents,
-                     interpret=None):
-    """Pytree version: returns (new_params, deltas)."""
-    pairs = jax.tree.map(
-        lambda x, g, z: prox_update(x, g, z, tau=tau, rho=rho,
-                                    num_walks=num_walks,
-                                    num_agents=num_agents,
-                                    interpret=interpret),
-        xs, gs, zsums)
-    new = jax.tree.map(lambda p: p[0], pairs,
-                       is_leaf=lambda p: isinstance(p, tuple))
-    delta = jax.tree.map(lambda p: p[1], pairs,
-                         is_leaf=lambda p: isinstance(p, tuple))
-    return new, delta
 
 
 # ---------------------------------------------------------------------------

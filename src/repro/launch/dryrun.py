@@ -104,7 +104,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool = False,
         raw_batch = input_specs(cfg, shape)
         b_sh = batch_shardings(mesh, raw_batch,
                                batch_axes=("agent", "replica"))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step_fn,
                 in_shardings=(p_sh, o_sh, b_sh, None),
@@ -134,7 +134,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool = False,
         st_sh = state_shardings(mesh, state_shapes)
         b_sh = train_batch_shardings(mesh, batch_shapes)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 train_step,
                 in_shardings=(st_sh, b_sh, None),
@@ -152,7 +152,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool = False,
     elif shape.kind == "prefill":
         mesh = make_production_mesh(multi_pod=multi_pod)
         batch_shapes = input_specs(cfg, shape)
-        with mesh:
+        with jax.set_mesh(mesh):
             fn, (p_sh, b_sh) = make_prefill_step(model, mesh, batch_shapes)
             params_shapes = jax.eval_shape(
                 model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -167,7 +167,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool = False,
         token_shapes = input_specs(cfg, shape)["token"]
         cache_shapes = jax.eval_shape(
             lambda: model.init_cache(shape.global_batch, shape.seq_len))
-        with mesh:
+        with jax.set_mesh(mesh):
             fn, (p_sh, t_sh, c_sh) = make_decode_step(
                 model, mesh, token_shapes, cache_shapes)
             params_shapes = jax.eval_shape(
@@ -191,8 +191,6 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool = False,
     coll_by_op = {k: v * chips for k, v in stats["collectives"].items()}
     coll_counts = stats["collective_counts"]
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):     # older jaxlib: [dict]
-        xla_cost = xla_cost[0] if xla_cost else {}
 
     mem = compiled.memory_analysis()
     mem_info = {}

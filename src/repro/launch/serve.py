@@ -124,7 +124,9 @@ def main():
     from repro.configs import get_config, get_smoke
     from repro.models import build_model
     from repro.serve import Engine, bucket_length
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

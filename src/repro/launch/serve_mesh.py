@@ -35,10 +35,12 @@ of all up front — the load pattern where overlapped admission
 (`--no-overlap` to disable) earns its keep, since prefills then land
 while decode batches are busy rather than in one initial burst.
 
-CPU multi-process collectives use jax's gloo backend
-(`jax_cpu_collectives_implementation`); on TPU/GPU pods
-`jax.distributed.initialize` picks the native transport and the same
-child code runs unchanged.
+This driver is a CPU program by design, not a chip path: each child
+forces `JAX_PLATFORMS=cpu` and a host device count, and its collectives
+run over jax's gloo backend (`jax_cpu_collectives_implementation`).  A
+chip holds one process at a time, so these children could not share
+one; serving on a TPU runs `repro.serve.Engine` in one process
+(`chip_smoke.py`, `repro.launch.serve`).
 """
 from __future__ import annotations
 
@@ -132,7 +134,8 @@ def _digest(done):
 
 
 def run_child(args) -> int:
-    # env must be set before jax initializes a backend
+    # a CPU child by design (see the module docstring); env must be set
+    # before jax initializes a backend
     os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (

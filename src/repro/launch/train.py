@@ -1,17 +1,142 @@
 """End-to-end API-BCD decentralized LM training driver.
 
-On a real TPU pod this runs on the production mesh; on CPU it forces a
-host device count so the agent ring exists (demo scale). Example:
+The agent ring is laid over the devices it is given (all of
+`jax.devices()` from the command line); on CPU, `--devices` forces a
+host device count so the ring exists (demo scale). Example:
 
     PYTHONPATH=src python -m repro.launch.train \
         --arch qwen2-0.5b --smoke --agents 4 --walks 2 --steps 50 \
         --batch-per-agent 4 --seq 128 --devices 8
 
-Writes checkpoints and a loss log.
+`Superstep` is the same path as a callable that takes its device list
+(`chip_smoke.py` drives it on one chip). Writes checkpoints and a loss
+log.
 """
 import argparse
+import contextlib
 import os
-import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.configs.base import TrainConfig
+from repro.data.tokens import agent_batches
+from repro.dist.sharding import state_shardings, train_batch_shardings
+from repro.dist.trainer import init_train_state, make_train_step
+from repro.models import build_model
+
+
+class Superstep:
+    """The API-BCD superstep over `devices`, driven one step at a time.
+
+    The agents are laid on an ("agent", "replica", "model") mesh over
+    the devices; on a single device there is no mesh and the agents are
+    vmapped there.  The state is initialised straight into its layout
+    (no full copy on one device first) and donated to every step.
+    place=False leaves `state` abstract (shapes with their shardings),
+    so `lower(abstract_batch())` compiles the step without allocating.
+    """
+
+    def __init__(self, cfg, devices, *, agents, walks, model_parallel=1,
+                 batch_per_agent=4, seq=128, tau=0.05, rho=20.0,
+                 paper_faithful=False, seed=0, place=True):
+        devices = list(devices)
+        a, mp = agents, model_parallel
+        self.model = build_model(cfg)
+        self.tcfg = TrainConfig(num_agents=a, model_parallel=mp,
+                                num_walks=walks, tau=tau, rho=rho,
+                                accumulate_between_visits=not paper_faithful)
+        self.batches = agent_batches(cfg.vocab_size, a, batch_per_agent, seq,
+                                     seed=seed)
+        init = lambda key: init_train_state(self.model, self.tcfg, key)
+        shapes = jax.eval_shape(init, jax.random.PRNGKey(seed))
+        batch = {k: jax.ShapeDtypeStruct((a, batch_per_agent, seq),
+                                         jnp.int32)
+                 for k in ("tokens", "targets")}
+        if len(devices) == 1:
+            self.mesh = None
+            one = SingleDeviceSharding(devices[0])
+            st_sh = jax.tree.map(lambda _: one, shapes)
+            self._batch_sh = jax.tree.map(lambda _: one, batch)
+        else:
+            self.mesh = agent_mesh(devices, a, mp)
+            st_sh = state_shardings(self.mesh, shapes)
+            self._batch_sh = train_batch_shardings(self.mesh, batch)
+        self._batch_shapes = batch
+        if place:
+            self.state = jax.jit(init, out_shardings=st_sh)(
+                jax.random.PRNGKey(seed))
+        else:
+            self.state = _abstract(shapes, st_sh)
+        self.train_step = jax.jit(make_train_step(self.model, self.tcfg),
+                                  out_shardings=(st_sh, None),
+                                  donate_argnums=(0,))
+
+    def _mesh_ctx(self):
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
+    def next_batch(self):
+        toks, targs = next(self.batches)
+        return jax.device_put({"tokens": toks, "targets": targs},
+                              self._batch_sh)
+
+    def abstract_batch(self):
+        return _abstract(self._batch_shapes, self._batch_sh)
+
+    def lower(self, batch, step=0):
+        with self._mesh_ctx():
+            return self.train_step.lower(self.state, batch, jnp.int32(step))
+
+    def step(self, step, batch=None):
+        """One superstep; returns its metrics (device arrays)."""
+        if batch is None:
+            batch = self.next_batch()
+        with self._mesh_ctx():
+            self.state, metrics = self.train_step(self.state, batch,
+                                                  jnp.int32(step))
+        return metrics
+
+
+def agent_mesh(devices, agents, model_parallel):
+    """("agent", "replica", "model") mesh over `devices`; the replica
+    axis takes what the agents and model parallelism leave."""
+    replica = len(devices) // (agents * model_parallel)
+    assert agents * model_parallel * replica == len(devices), (
+        agents, model_parallel, len(devices))
+    return Mesh(np.array(devices).reshape(agents, replica, model_parallel),
+                ("agent", "replica", "model"))
+
+
+def _abstract(shapes, shardings):
+    return jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, shardings)
+
+
+def _run_baseline(args, model, mesh):
+    """The synchronous all-reduce data-parallel baseline on the mesh."""
+    from repro.dist.trainer import make_dp_baseline_step
+    from repro.optim import adamw, constant
+
+    opt = adamw(weight_decay=0.0)
+    params = model.init(jax.random.PRNGKey(0))
+    opt_state = opt.init(params)
+    step_fn = jax.jit(make_dp_baseline_step(model, opt, constant(3e-4)))
+    batches = agent_batches(model.cfg.vocab_size, args.agents,
+                            args.batch_per_agent, args.seq, seed=0)
+    with jax.set_mesh(mesh):
+        for step in range(args.steps):
+            toks, targs = next(batches)
+            batch = {"tokens": jnp.asarray(toks.reshape(-1, args.seq)),
+                     "targets": jnp.asarray(targs.reshape(-1, args.seq))}
+            params, opt_state, metrics = step_fn(params, opt_state,
+                                                 batch, step)
+            if step % args.log_every == 0:
+                print(f"step {step:4d}  loss {float(metrics['loss']):.4f}")
 
 
 def main():
@@ -46,74 +171,34 @@ def main():
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh
-
     from repro.checkpoint import save_checkpoint
-    from repro.utils.logging import MetricLogger
     from repro.configs import get_config, get_smoke
-    from repro.configs.base import TrainConfig
-    from repro.data.tokens import agent_batches
-    from repro.dist.sharding import state_shardings, train_batch_shardings
-    from repro.dist.trainer import init_train_state, make_train_step
-    from repro.models import build_model
-    from repro.optim import adamw, constant
-    from repro.dist.trainer import make_dp_baseline_step
+    from repro.utils.compile_cache import enable_compile_cache
+    from repro.utils.logging import MetricLogger
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    model = build_model(cfg)
-
-    n_dev = len(jax.devices())
+    devices = jax.devices()
     a, mp = args.agents, args.model_parallel
-    replica = n_dev // (a * mp)
-    assert a * mp * replica == n_dev, (a, mp, n_dev)
-    mesh = Mesh(np.array(jax.devices()).reshape(a, replica, mp),
-                ("agent", "replica", "model"))
-    print(f"mesh: agents={a} replica={replica} model={mp}  arch={cfg.name}")
-
-    tcfg = TrainConfig(num_agents=a, model_parallel=mp,
-                       num_walks=args.walks, tau=args.tau, rho=args.rho,
-                       accumulate_between_visits=not args.paper_faithful)
-
-    batches = agent_batches(cfg.vocab_size, a, args.batch_per_agent,
-                            args.seq, seed=0)
+    print(f"mesh: agents={a} replica={len(devices) // (a * mp)} "
+          f"model={mp}  arch={cfg.name}")
 
     if args.baseline:
-        opt = adamw(weight_decay=0.0)
-        params = model.init(jax.random.PRNGKey(0))
-        opt_state = opt.init(params)
-        step_fn = jax.jit(make_dp_baseline_step(model, opt,
-                                                constant(3e-4)))
-        with mesh:
-            for step in range(args.steps):
-                toks, targs = next(batches)
-                batch = {"tokens": jnp.asarray(toks.reshape(-1, args.seq)),
-                         "targets": jnp.asarray(targs.reshape(-1, args.seq))}
-                params, opt_state, metrics = step_fn(params, opt_state,
-                                                     batch, step)
-                if step % args.log_every == 0:
-                    print(f"step {step:4d}  loss {float(metrics['loss']):.4f}")
-        return
+        return _run_baseline(args, build_model(cfg),
+                             agent_mesh(devices, a, mp))
 
-    state = init_train_state(model, tcfg, key=jax.random.PRNGKey(0))
-    st_sh = state_shardings(mesh, jax.eval_shape(lambda: state))
-    state = jax.device_put(state, st_sh)
-    train_step = jax.jit(make_train_step(model, tcfg), donate_argnums=(0,))
-
+    run = Superstep(cfg, devices, agents=a, walks=args.walks,
+                    model_parallel=mp, batch_per_agent=args.batch_per_agent,
+                    seq=args.seq, tau=args.tau, rho=args.rho,
+                    paper_faithful=args.paper_faithful)
     logger = MetricLogger(args.log_dir, echo_every=args.log_every)
-    with mesh:
-        for step in range(args.steps):
-            toks, targs = next(batches)
-            batch = {"tokens": jnp.asarray(toks),
-                     "targets": jnp.asarray(targs)}
-            state, metrics = train_step(state, batch, jnp.int32(step))
-            logger.log(step, loss=metrics["loss"], nll=metrics["nll"])
+    for step in range(args.steps):
+        metrics = run.step(step)
+        logger.log(step, loss=metrics["loss"], nll=metrics["nll"])
     logger.close()
 
     if args.checkpoint_dir:
-        save_checkpoint(args.checkpoint_dir, state, step=args.steps,
+        save_checkpoint(args.checkpoint_dir, run.state, step=args.steps,
                         metadata={"arch": cfg.name})
         print("checkpoint written to", args.checkpoint_dir)
 

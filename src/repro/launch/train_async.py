@@ -111,7 +111,10 @@ def parse_straggle(spec: str, num_procs: int):
 
 
 def run_child(args) -> int:
-    # env must be set before jax initializes a backend
+    # a CPU child by design: the convex reference problems run in
+    # float64 host code and the processes talk over gloo, so this is
+    # not a chip path (a chip holds one process at a time); env must be
+    # set before jax initializes a backend
     os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax

@@ -1,38 +1,32 @@
-"""Best-effort GSPMD sharding hints for model internals.
+"""GSPMD sharding hints for model internals.
 
 GSPMD occasionally partitions a contraction dimension inside scan bodies
 (the stacked loop buffers lose the propagated head sharding), turning every
 attention chunk into a partial-sum all-reduce. `shard_hint` pins the
-preferred layout when — and only when — a compatible mesh is active; it is
-a silent no-op otherwise (single-device tests, interpret mode, mismatched
-axis sizes), so model code stays mesh-agnostic.
+preferred layout when a mesh is set (`jax.set_mesh`) and one of its axes
+fits; without a mesh, or when no axis divides the dimension, it returns x
+unchanged, so model code stays mesh-agnostic.  A constraint that is
+placed and then fails raises.
 """
 from __future__ import annotations
+
+import os
 
 import jax
 from jax.sharding import PartitionSpec
 
 
-import os
-
-
 def _active_mesh():
     if os.environ.get("REPRO_DISABLE_HINTS"):
         return None
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return None
-        return mesh
-    except Exception:
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def shard_hint(x, *dim_axes):
     """Constrain x's sharding: dim_axes[i] = mesh axis name, a tuple of
     candidate names (first match wins), or None. Dims beyond len(dim_axes)
-    stay unspecified. No-op when no mesh is active or nothing matches."""
+    stay unspecified. No-op when no mesh is set or nothing matches."""
     mesh = _active_mesh()
     if mesh is None:
         return x
@@ -56,7 +50,4 @@ def shard_hint(x, *dim_axes):
     spec += [None] * (x.ndim - len(spec))
     if not any(spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))

@@ -391,9 +391,6 @@ class Engine:
             lambda: model.init_arena(self.max_batch, self.capacity,
                                      dtype=cache_dtype))
 
-        # donation avoids a full arena/pool copy per step; CPU jax only
-        # warns, so gate it on the backend.
-        donate = jax.default_backend() != "cpu"
         self._repl = None   # replicated sharding for mirrors (mesh only)
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -448,14 +445,14 @@ class Engine:
             else:
                 self._prefill = _shared_jit(
                     model, "prefill_chunk_into_blocks_token",
-                    donate_argnums=(5,) if donate else ())
+                    donate_argnums=(5,))
                 self._decode = _shared_jit(
                     model, "decode_rows_paged_tokens",
-                    donate_argnums=(2,) if donate else ())
+                    donate_argnums=(2,))
                 if self.overlap_mode == "fused":
                     self._mixed = _shared_jit(
                         model, "mixed_step_paged_tokens",
-                        donate_argnums=(2,) if donate else ())
+                        donate_argnums=(2,))
                 self._caches = model.init_pool(self.num_blocks,
                                                self.block_size,
                                                dtype=cache_dtype)
@@ -477,13 +474,12 @@ class Engine:
                 out_shardings=c_sh)()
         else:
             self._prefill = _shared_jit(model, "prefill_into_slot_token",
-                                        donate_argnums=(4,) if donate else ())
+                                        donate_argnums=(4,))
             self._decode = _shared_jit(model, "decode_rows_tokens",
-                                       donate_argnums=(2,) if donate else ())
+                                       donate_argnums=(2,))
             if self.overlap_mode == "fused":
                 self._mixed = _shared_jit(model, "mixed_step_tokens",
-                                          donate_argnums=(2,) if donate
-                                          else ())
+                                          donate_argnums=(2,))
             self._caches = model.init_arena(self.max_batch, self.capacity,
                                             dtype=cache_dtype)
 

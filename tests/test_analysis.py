@@ -25,7 +25,7 @@ from repro.analysis import baseline as baseline_mod
 from repro.analysis.pragmas import parse_pragmas
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
-LINT_TREES = ["src", "tests", "benchmarks", "examples"]
+LINT_TREES = ["src", "tests", "benchmarks", "examples", "chip_smoke.py"]
 
 
 def lint(src, path="fixture.py"):

@@ -37,6 +37,21 @@ def test_prox_update(shape, dtype):
                                **TOL[dtype])
 
 
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Kernels are interpreted on the CPU, compiled on the TPU, and
+    refused elsewhere rather than silently interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops._interpret_default(False) is False
+    if interpret is None:
+        with pytest.raises(NotImplementedError, match="gpu"):
+            ops._interpret_default(None)
+    else:
+        assert ops._interpret_default(None) is interpret
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

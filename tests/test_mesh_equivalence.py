@@ -78,7 +78,7 @@ def np_step(x, tok, zh, step):
     tok_new = np.roll(tok_new, 1, axis=0)
     return x_new, tok_new, zh_new
 
-with mesh:
+with jax.set_mesh(mesh):
     for step in range(3 * A):
         state, metrics = step_fn(state, batch, jnp.int32(step))
         x, tok, zh = np_step(x, tok, zh, step)

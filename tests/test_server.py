@@ -235,32 +235,58 @@ def test_bucket_length_floor_and_boundaries():
 # ---------------------------------------------------------------------------
 
 
-def _raw_greedy_loop(model, params, prompt, budget):
-    """Reference: single-request prefill + decode_step loop."""
+# Greedy tokens from two different compiled paths (the paged engine and
+# the raw prefill + decode_step loop, or the paged and arena engines) may
+# part where two logits tie to within bf16 rounding: the logits come out
+# of a bf16 unembedding (8 significand bits), and a path that rounds an
+# intermediate differently moves a logit by one unit in the last place.
+# Measured on the smoke model: the raw loop picks token 440 at 0.6016
+# where the paged engine picks 395 at 0.5977, a margin of one ulp.  Such
+# paths are therefore compared by teacher forcing: a path's tokens are
+# fed through the raw loop, and at every step the token's logit must be
+# within BF16_TIE_ULPS ulps (of that step's top logit) of the maximum.
+# Bitwise checks stay where the same compiled programs rerun.
+BF16_TIE_ULPS = 2
+
+
+def _teacher_forced_logits(model, params, prompt, tokens):
+    """[len(tokens), V] f32 logits of the raw loop when it is fed
+    `tokens`: row i scores the choice of tokens[i]."""
     from functools import partial
     plen = len(prompt)
-    prefill = jax.jit(partial(model.prefill, cache_len=plen + budget))
+    prefill = jax.jit(partial(model.prefill, cache_len=plen + len(tokens)))
     decode = jax.jit(model.decode_step)
     logits, caches = prefill(params, {"tokens": jnp.asarray(prompt[None])})
-    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-    out = [int(tok[0, 0])]
-    for i in range(1, budget):
+    rows = [np.asarray(logits[0, -1])]
+    for i in range(1, len(tokens)):
+        tok = jnp.asarray([[tokens[i - 1]]], jnp.int32)
         logits, caches = decode(params, tok, caches, jnp.int32(plen + i - 1))
-        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-        out.append(int(tok[0, 0]))
-    return np.asarray(out, np.int32)
+        rows.append(np.asarray(logits[0, -1]))
+    return np.stack(rows)
+
+
+def _assert_near_greedy(model, params, prompt, tokens):
+    """Every token is the raw loop's argmax up to a bf16 tie."""
+    tokens = np.asarray(tokens)
+    logits = _teacher_forced_logits(model, params, prompt, tokens)
+    top = logits.max(-1)
+    gap = top - logits[np.arange(len(tokens)), tokens]
+    ulp = 2.0 ** (np.floor(np.log2(np.abs(top))) - 7)
+    bad = np.nonzero(gap > BF16_TIE_ULPS * ulp)[0]
+    assert bad.size == 0, (
+        f"tokens at {bad.tolist()} trail the raw loop's top logit by "
+        f"{gap[bad].tolist()} (> {BF16_TIE_ULPS} bf16 ulps)")
 
 
 def test_engine_paged_longer_than_slot_gqa(served):
     """Acceptance: plen + max_new_tokens > slot capacity (but within the
-    pool budget) completes through Engine(paged=True), bit-identical to
-    the raw single-request decode loop — with another request in flight
-    so pool scatter/gather interleaves across rows."""
+    pool budget) completes through Engine(paged=True), greedy under the
+    raw single-request decode loop up to bf16 ties — with another
+    request in flight so pool scatter/gather interleaves across rows."""
     cfg, model, params = served
     rng = np.random.default_rng(20)
     prompt = rng.integers(0, cfg.vocab_size, (10,))
     budget = 20                         # 10 + 20 = 30 > capacity 16
-    want = _raw_greedy_loop(model, params, prompt, budget)
 
     eng = Engine(model, params, max_batch=2, max_len=16, paged=True,
                  block_size=8, prefill_chunk=4)
@@ -270,7 +296,8 @@ def test_engine_paged_longer_than_slot_gqa(served):
     uid = eng.submit(prompt, max_new_tokens=budget)
     eng.submit(rng.integers(0, cfg.vocab_size, (5,)), max_new_tokens=6)
     outs = {r.uid: r.output for r in eng.run()}
-    np.testing.assert_array_equal(outs[uid], want)
+    assert len(outs[uid]) == budget
+    _assert_near_greedy(model, params, prompt, outs[uid])
     assert eng.free_blocks == eng.num_blocks    # all blocks returned
 
 
@@ -289,7 +316,6 @@ def test_engine_paged_longer_than_slot_mla():
     rng = np.random.default_rng(21)
     prompt = rng.integers(0, cfg.vocab_size, (9,))
     budget = 18                         # 9 + 18 = 27 > capacity 16
-    want = _raw_greedy_loop(model, params, prompt, budget)
 
     eng = Engine(model, params, max_batch=2, max_len=16, paged=True,
                  block_size=4, prefill_chunk=4)
@@ -297,13 +323,16 @@ def test_engine_paged_longer_than_slot_mla():
     uid = eng.submit(prompt, max_new_tokens=budget)
     eng.submit(rng.integers(0, cfg.vocab_size, (5,)), max_new_tokens=8)
     outs = {r.uid: r.output for r in eng.run()}
-    np.testing.assert_array_equal(outs[uid], want)
+    assert len(outs[uid]) == budget
+    _assert_near_greedy(model, params, prompt, outs[uid])
     assert eng.free_blocks == eng.num_blocks
 
 
 def test_engine_paged_matches_arena_mixed_lengths(served):
-    """Paged vs arena bit-identity on a mixed-length workload that fits
-    both: the storage backend is semantically inert."""
+    """Paged and arena engines on a mixed-length workload that fits
+    both: every request gets its full budget from each, and both are
+    greedy under the raw loop up to bf16 ties — the storage backend is
+    semantically inert."""
     cfg, model, params = served
     rng = np.random.default_rng(22)
     reqs = [(rng.integers(0, cfg.vocab_size, (int(n),)), int(b))
@@ -315,8 +344,10 @@ def test_engine_paged_matches_arena_mixed_lengths(served):
     up = [paged.submit(p, max_new_tokens=b) for p, b in reqs]
     oa = {r.uid: r.output for r in arena.run()}
     op = {r.uid: r.output for r in paged.run()}
-    for a, b in zip(ua, up):
-        np.testing.assert_array_equal(oa[a], op[b])
+    for (prompt, budget), a, b in zip(reqs, ua, up):
+        assert len(oa[a]) == len(op[b]) == budget
+        _assert_near_greedy(model, params, prompt, oa[a])
+        _assert_near_greedy(model, params, prompt, op[b])
     assert paged.free_blocks == paged.num_blocks
 
 
@@ -501,26 +532,30 @@ def test_engine_preemption_during_replay_bit_identity(served):
     tokens (`_replay` non-empty) must re-admit cleanly: gen_prefix is
     not duplicated (the interrupted replay contributed nothing to
     `_gen`) and the final output is bitwise identical to an unpreempted
-    run.  Scenario: an older long request keeps crossing block
-    boundaries, so the younger request is evicted, re-admitted, and
-    evicted again before its replay drains."""
+    run of the same paged configuration (the same compiled programs).
+    Scenario: an older long request keeps crossing block boundaries, so
+    the younger request is evicted, re-admitted, and evicted again
+    before its replay drains."""
     cfg, model, params = served
     rng = np.random.default_rng(34)
     pa = rng.integers(0, cfg.vocab_size, (4,))
     pb = rng.integers(0, cfg.vocab_size, (4,))
     budget = 24
+    geometry = dict(max_batch=2, max_len=32, paged=True, block_size=4,
+                    num_blocks=7, prefill_chunk=4)
 
+    # alone, a request's worst case (4 + 24 - 1 = 27 tokens, 7 blocks)
+    # fits the pool: the references are never evicted
     refs = {}
     for key, p in (("a", pa), ("b", pb)):
-        r = Engine(model, params, max_batch=1, max_len=32)
+        r = Engine(model, params, **geometry)
         r.submit(p, max_new_tokens=budget)
         refs[key] = r.run()[0].output
+        assert r.num_preemptions == 0
 
-    # worst case 7 blocks each (4 + 24 - 1 = 27 tokens / 4), pool 7:
-    # optimistic admission takes both, then A's growth repeatedly
-    # evicts B (LIFO) — including while B is mid-replay
-    eng = Engine(model, params, max_batch=2, max_len=32, paged=True,
-                 block_size=4, num_blocks=7, prefill_chunk=4)
+    # together, optimistic admission takes both, then A's growth
+    # repeatedly evicts B (LIFO) — including while B is mid-replay
+    eng = Engine(model, params, **geometry)
     assert eng.paged and eng.preemption == "recompute"
     ua = eng.submit(pa, max_new_tokens=budget)
     ub = eng.submit(pb, max_new_tokens=budget)

@@ -65,7 +65,7 @@ state = init_train_state(model, tcfg, key=jax.random.PRNGKey(0))
 step_fn = jax.jit(make_train_step(model, tcfg), donate_argnums=(0,))
 batches = agent_batches(cfg.vocab_size, 4, 4, 64, seed=0)
 losses = []
-with mesh:
+with jax.set_mesh(mesh):
     for step in range(40):
         toks, targs = next(batches)
         state, m = step_fn(state, {"tokens": jnp.asarray(toks),
