@@ -24,6 +24,12 @@ A - M non-holding agents bit-untouched — the invariant
 `tests/dist_check_script.py` asserts.  The beyond-paper default
 accumulates every agent's gradient between visits and applies the mean
 at the next activation, so no batch is wasted on idle agents.
+
+Every line of the step sits under one `jax.named_scope`, which the
+compiled program keeps as each operation's `op_name`: apibcd.grad (the
+local gradients), .accumulate, .zsum, .prox (eq. 15 and 12b), .select
+(only token holders move), .token (12c) and .exchange (the ring hop);
+docs/dist.md reads them in a profiler trace.
 """
 from __future__ import annotations
 
@@ -144,51 +150,60 @@ def make_train_step(model, tcfg):
         params, token = state["params"], state["token"]
         zhat, gacc = state["zhat"], state["gacc"]
 
-        (losses, metr), grads = jax.vmap(grad_fn)(params, batch)
+        with jax.named_scope("apibcd.grad"):
+            (losses, metr), grads = jax.vmap(grad_fn)(params, batch)
+            metrics = {"loss": jnp.mean(losses),
+                       "nll": jnp.mean(metr["nll"]),
+                       "aux": jnp.mean(metr["aux"])}
 
-        rel = jnp.mod(jnp.arange(a) - step, a)
-        active = (rel % period) == 0             # [A] token-holding agents
-        walk_id = rel // period                  # which token sits here
+        with jax.named_scope("apibcd.select"):
+            rel = jnp.mod(jnp.arange(a) - step, a)
+            active = (rel % period) == 0         # [A] token-holding agents
+            walk_id = rel // period              # which token sits here
 
-        if accumulate:
-            gsum = jax.tree.map(jnp.add, gacc, grads)
-            # mean over the visit period (steady-state visit interval)
-            g_eff = jax.tree.map(lambda g: g / period, gsum)
-            gacc_new = jax.tree.map(
-                lambda g: jnp.where(_broadcast(active, g), 0.0, g), gsum)
-        else:
-            g_eff = grads
-            gacc_new = gacc
+        with jax.named_scope("apibcd.accumulate"):
+            if accumulate:
+                gsum = jax.tree.map(jnp.add, gacc, grads)
+                # mean over the visit period (steady-state visit interval)
+                g_eff = jax.tree.map(lambda g: g / period, gsum)
+                gacc_new = jax.tree.map(
+                    lambda g: jnp.where(_broadcast(active, g), 0.0, g), gsum)
+            else:
+                g_eff = grads
+                gacc_new = gacc
 
-        zsum = jax.tree.map(lambda z: z.sum(axis=1), zhat)
+        with jax.named_scope("apibcd.zsum"):
+            zsum = jax.tree.map(lambda z: z.sum(axis=1), zhat)
 
         # fused closed-form update (eq. 15) + token credit (eq. 12b)
-        x_full, d_full = _prox_update_tree(
-            params, g_eff, zsum, tau=tau, rho=rho, num_walks=m,
-            num_agents=a)
+        with jax.named_scope("apibcd.prox"):
+            x_full, d_full = _prox_update_tree(
+                params, g_eff, zsum, tau=tau, rho=rho, num_walks=m,
+                num_agents=a)
 
         # only token-holding agents move; inactive rows stay bit-identical
-        params_new = jax.tree.map(
-            lambda xf, x: jnp.where(_broadcast(active, x), xf, x),
-            x_full, params)
-        delta = jax.tree.map(
-            lambda d: jnp.where(_broadcast(active, d), d, 0.0), d_full)
-        token_new = jax.tree.map(jnp.add, token, delta)
+        with jax.named_scope("apibcd.select"):
+            params_new = jax.tree.map(
+                lambda xf, x: jnp.where(_broadcast(active, x), xf, x),
+                x_full, params)
+            delta = jax.tree.map(
+                lambda d: jnp.where(_broadcast(active, d), d, 0.0), d_full)
 
-        # zhat_{i, walk_id[i]} <- z (12c), for active slots only
-        wmask = active[:, None] & (jnp.arange(m)[None, :]
-                                   == walk_id[:, None])       # [A, M]
-        zhat_new = jax.tree.map(
-            lambda zh, t: jnp.where(
-                wmask.reshape((a, m) + (1,) * (zh.ndim - 2)), t[:, None],
-                zh),
-            zhat, token_new)
+        # the token credit, then zhat_{i, walk_id[i]} <- z (12c) for
+        # active slots only
+        with jax.named_scope("apibcd.token"):
+            token_new = jax.tree.map(jnp.add, token, delta)
+            wmask = active[:, None] & (jnp.arange(m)[None, :]
+                                       == walk_id[:, None])   # [A, M]
+            zhat_new = jax.tree.map(
+                lambda zh, t: jnp.where(
+                    wmask.reshape((a, m) + (1,) * (zh.ndim - 2)),
+                    t[:, None], zh),
+                zhat, token_new)
 
-        token_out = jax.tree.map(ring_shift, token_new)
+        with jax.named_scope("apibcd.exchange"):
+            token_out = jax.tree.map(ring_shift, token_new)
 
-        metrics = {"loss": jnp.mean(losses),
-                   "nll": jnp.mean(metr["nll"]),
-                   "aux": jnp.mean(metr["aux"])}
         return ({"params": params_new, "token": token_out,
                  "zhat": zhat_new, "gacc": gacc_new}, metrics)
 

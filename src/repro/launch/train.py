@@ -10,7 +10,8 @@ host device count so the ring exists (demo scale). Example:
 
 `Superstep` is the same path as a callable that takes its device list
 (`chip_smoke.py` drives it on one chip). Writes checkpoints and a loss
-log.
+log, and with --profile-dir a profiler trace (docs/dist.md, "Profiling
+the superstep").
 """
 import argparse
 import contextlib
@@ -26,6 +27,7 @@ from repro.data.tokens import agent_batches
 from repro.dist.sharding import state_shardings, train_batch_shardings
 from repro.dist.trainer import init_train_state, make_train_step
 from repro.models import build_model
+from repro.utils.hotpath import hot_loop
 
 
 class Superstep:
@@ -37,6 +39,11 @@ class Superstep:
     (no full copy on one device first) and donated to every step.
     place=False leaves `state` abstract (shapes with their shardings),
     so `lower(abstract_batch())` compiles the step without allocating.
+
+    Under `jax.profiler` each dispatch is a host span "apibcd.step"
+    (`StepTraceAnnotation`, numbered by the step) and each upload in
+    `next_batch` an "apibcd.batch_upload" span; inside the compiled step
+    the phases carry `apibcd.*` scopes (`repro.dist.trainer`).
     """
 
     def __init__(self, cfg, devices, *, agents, walks, model_parallel=1,
@@ -80,9 +87,10 @@ class Superstep:
         return jax.set_mesh(self.mesh)
 
     def next_batch(self):
-        toks, targs = next(self.batches)
-        return jax.device_put({"tokens": toks, "targets": targs},
-                              self._batch_sh)
+        with jax.profiler.TraceAnnotation("apibcd.batch_upload"):
+            toks, targs = next(self.batches)
+            return jax.device_put({"tokens": toks, "targets": targs},
+                                  self._batch_sh)
 
     def abstract_batch(self):
         return _abstract(self._batch_shapes, self._batch_sh)
@@ -91,11 +99,14 @@ class Superstep:
         with self._mesh_ctx():
             return self.train_step.lower(self.state, batch, jnp.int32(step))
 
+    @hot_loop
     def step(self, step, batch=None):
-        """One superstep; returns its metrics (device arrays)."""
+        """One superstep; returns its metrics (device arrays) without
+        waiting for them."""
         if batch is None:
             batch = self.next_batch()
-        with self._mesh_ctx():
+        span = jax.profiler.StepTraceAnnotation("apibcd.step", step_num=step)
+        with span, self._mesh_ctx():
             self.state, metrics = self.train_step(self.state, batch,
                                                   jnp.int32(step))
         return metrics
@@ -164,6 +175,10 @@ def main():
     ap.add_argument("--log-dir", default=None,
                     help="write JSONL metrics here")
     ap.add_argument("--log-every", type=int, default=5)
+    ap.add_argument("--profile-dir", default=None,
+                    help="trace every step after the first (which "
+                         "compiles) with jax.profiler into this "
+                         "directory, with a Perfetto copy")
     args = ap.parse_args()
 
     if args.devices:
@@ -193,8 +208,16 @@ def main():
                     paper_faithful=args.paper_faithful)
     logger = MetricLogger(args.log_dir, echo_every=args.log_every)
     for step in range(args.steps):
+        if step == 1 and args.profile_dir:
+            jax.block_until_ready(run.state)
+            jax.profiler.start_trace(args.profile_dir,
+                                     create_perfetto_trace=True)
         metrics = run.step(step)
         logger.log(step, loss=metrics["loss"], nll=metrics["nll"])
+    if args.profile_dir and args.steps > 1:
+        jax.block_until_ready(run.state)
+        jax.profiler.stop_trace()
+        print("profile written to", args.profile_dir)
     logger.close()
 
     if args.checkpoint_dir:

@@ -316,33 +316,43 @@ def _cast(cfg, params):
 
 def train_loss(cfg, params, batch, window=0, remat=True):
     """batch: {tokens [B,S], targets [B,S], loss_mask [B,S](opt),
-    patches [B,P,D](opt, VLM prefix)}. Returns (loss, metrics)."""
+    patches [B,P,D](opt, VLM prefix)}. Returns (loss, metrics).
+
+    Its phases are named in the compiled program (`jax.named_scope`):
+    model.embed, model.blocks (the layer scans) and model.head (logits
+    and loss)."""
     params = _cast(cfg, params)
     tokens = batch["tokens"]
-    x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.compute_dtype))
-    n_prefix = 0
-    if "patches" in batch and batch["patches"] is not None:
-        patches = batch["patches"].astype(x.dtype)
-        n_prefix = patches.shape[1]
-        x = jnp.concatenate([patches, x], axis=1)
-    b, s, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x, _, aux = forward(cfg, params, x, positions=positions, mode="train",
-                        window=window, remat=remat)
-    x = x[:, n_prefix:]
-    logits = logits_fn(cfg, params, x).astype(jnp.float32)
-    targets = batch["targets"]
-    # shard-friendly CE: reductions over the (vocab-sharded) last axis
-    # partition cleanly; take_along_axis would force logits replication
-    m = jax.lax.stop_gradient(logits.max(axis=-1))
-    logz = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
-    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=logits.dtype)
-    gold = jnp.sum(logits * onehot, axis=-1)
-    nll = logz - gold
-    mask = batch.get("loss_mask")
-    if mask is None:
-        mask = jnp.ones_like(nll)
-    loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with jax.named_scope("model.embed"):
+        x = embed(params["embed"], tokens).astype(
+            jnp.dtype(cfg.compute_dtype))
+        n_prefix = 0
+        if "patches" in batch and batch["patches"] is not None:
+            patches = batch["patches"].astype(x.dtype)
+            n_prefix = patches.shape[1]
+            x = jnp.concatenate([patches, x], axis=1)
+        b, s, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    with jax.named_scope("model.blocks"):
+        x, _, aux = forward(cfg, params, x, positions=positions,
+                            mode="train", window=window, remat=remat)
+    with jax.named_scope("model.head"):
+        x = x[:, n_prefix:]
+        logits = logits_fn(cfg, params, x).astype(jnp.float32)
+        targets = batch["targets"]
+        # shard-friendly CE: reductions over the (vocab-sharded) last axis
+        # partition cleanly; take_along_axis would force logits replication
+        m = jax.lax.stop_gradient(logits.max(axis=-1))
+        logz = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]),
+                                   axis=-1))
+        onehot = jax.nn.one_hot(targets, logits.shape[-1],
+                                dtype=logits.dtype)
+        gold = jnp.sum(logits * onehot, axis=-1)
+        nll = logz - gold
+        mask = batch.get("loss_mask")
+        if mask is None:
+            mask = jnp.ones_like(nll)
+        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     return loss + aux, {"nll": loss, "aux": aux}
 
 

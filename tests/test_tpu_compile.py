@@ -81,6 +81,30 @@ def test_train_superstep_compiles_full_width(topo):
     assert _planned_bytes(lowered.compile()) < V5E_HBM_BYTES
 
 
+def test_train_superstep_kernel_sits_under_its_phase_scope(topo):
+    """Compiled for the chip, each prox_update kernel (the Pallas call,
+    `tpu_custom_call`, named after it) carries the apibcd.prox scope and
+    each layer scan apibcd.grad's (the smoke config: the same program at
+    small widths)."""
+    import re
+
+    from repro.configs import get_smoke
+    run = Superstep(get_smoke("qwen2-0.5b"), topo.devices[:1], agents=2,
+                    walks=2, batch_per_agent=2, seq=16, place=False)
+    hlo = run.lower(run.abstract_batch()).compile().as_text()
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels
+    for ln in kernels:
+        assert re.match(r"\s*%prox_update\.\d+ = ", ln), ln[:80]
+        assert 'op_name="jit(step_fn)/apibcd.prox/prox_update/' in ln
+    loops = [ln for ln in hlo.splitlines() if " while(" in ln
+             and "model.blocks" in ln]
+    assert len(loops) == 2
+    assert all(re.search(r'op_name="[^"]*/apibcd\.grad/[^"]*model\.blocks',
+                         ln) for ln in loops)
+
+
 def test_engine_decode_step_compiles_full_width(one_chip):
     """The slot-arena decode step the engine jits (`decode_rows_tokens`,
     arena donated) at published widths, 4 slots of 512 positions."""
