@@ -175,11 +175,13 @@ def make_train_step(model, tcfg):
         with jax.named_scope("apibcd.zsum"):
             zsum = jax.tree.map(lambda z: z.sum(axis=1), zhat)
 
-        # fused closed-form update (eq. 15) + token credit (eq. 12b)
+        # fused closed-form update (eq. 15) + token credit (eq. 12b);
+        # with one agent per walk every agent is active, nothing reads
+        # the old x after the update, and x_new can take its buffer
         with jax.named_scope("apibcd.prox"):
             x_full, d_full = _prox_update_tree(
                 params, g_eff, zsum, tau=tau, rho=rho, num_walks=m,
-                num_agents=a)
+                num_agents=a, in_place=period == 1)
 
         # only token-holding agents move; inactive rows stay bit-identical
         with jax.named_scope("apibcd.select"):

@@ -16,7 +16,7 @@ from repro.kernels.decode_attention import (decode_attention_grouped,
                                             decode_attention_paged_grouped,
                                             decode_attention_ring_grouped)
 from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.prox_update import LANE, prox_update_2d
+from repro.kernels.prox_update import SUBLANES, prox_update_nd
 from repro.kernels.rglru_scan import rglru_scan_bsw
 from repro.kernels.rwkv6_scan import rwkv6_scan_bh
 
@@ -40,30 +40,28 @@ def _interpret_default(interpret):
 
 
 def prox_update(x, g, zsum, *, tau, rho, num_walks, num_agents,
-                interpret=None):
+                in_place=False, interpret=None):
     """Fused gAPI-BCD update on a single array (any shape).
 
-    Returns (x_new, delta) — see kernels/prox_update.py."""
+    Returns (x_new, delta) — see kernels/prox_update.py.  The kernel
+    blocks the array as it lies: leading dims fold into rows only where
+    the second-minor dim fills whole 8-row tiles, so the fold is a
+    bitcast, and are otherwise walked by the kernel's grid; a 0-d or 1-D
+    array is viewed as [1, n].  in_place: x_new takes x's buffer, for
+    callers that no longer read x."""
     interpret = _interpret_default(interpret)
     shape = x.shape
-    n = x.size
-    pad = (-n) % LANE
-    def tile(a):
-        flat = a.reshape(-1)
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        return flat.reshape(-1, LANE)
-    x2, g2, z2 = tile(x), tile(g), tile(zsum)
-    x_new, delta = prox_update_2d(x2, g2, z2, tau=tau, rho=rho,
-                                  num_walks=num_walks,
-                                  num_agents=num_agents,
-                                  interpret=interpret)
-    def untile(a, dtype):
-        flat = a.reshape(-1)
-        if pad:
-            flat = flat[:n]
-        return flat.reshape(shape).astype(dtype)
-    return untile(x_new, x.dtype), untile(delta, jnp.float32)
+    if x.ndim < 2:
+        view = (1, x.size)
+    elif shape[-2] % SUBLANES == 0:
+        view = (-1, shape[-1])
+    else:
+        view = shape
+    x_new, delta = prox_update_nd(
+        x.reshape(view), g.reshape(view), zsum.reshape(view), tau=tau,
+        rho=rho, num_walks=num_walks, num_agents=num_agents,
+        in_place=in_place, interpret=interpret)
+    return x_new.reshape(shape), delta.reshape(shape)
 
 
 # ---------------------------------------------------------------------------

@@ -21,20 +21,45 @@ TOL = {jnp.float32: dict(rtol=1e-5, atol=1e-5),
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(64,), (300,), (8, 130), (3, 5, 257)])
+# (): a scalar; (2, 3, 16, 256): a stacked leaf folded into rows;
+# (3, 5, 257): second-minor dim off the 8-row tile, leading dim in the
+# grid; (3, 33000): last dim wider than one column block (ragged);
+# (2, 293, 896): ragged last row block under a grid-walked leading dim;
+# (4104, 64): a last dim narrower than a lane tile, in two row blocks
+@pytest.mark.parametrize("shape", [(64,), (300,), (8, 130), (3, 5, 257),
+                                   (), (2, 3, 16, 256), (3, 33000),
+                                   (2, 293, 896), (4104, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_prox_update(shape, dtype):
+@pytest.mark.parametrize("in_place", [False, True])
+def test_prox_update(shape, dtype, in_place):
     rng = np.random.default_rng(0)
     x = _rand(rng, shape, dtype)
     g = _rand(rng, shape, dtype)
     z = _rand(rng, shape, dtype)
     args = dict(tau=0.1, rho=1.0, num_walks=4, num_agents=16)
-    xk, dk = ops.prox_update(x, g, z, **args, interpret=True)
     xr, dr = ref.prox_update(x, g, z, **args)
+    xk, dk = jax.jit(lambda *a: ops.prox_update(
+        *a, **args, in_place=in_place, interpret=True),
+        donate_argnums=0 if in_place else ())(x, g, z)
     np.testing.assert_allclose(np.asarray(xk, np.float32),
                                np.asarray(xr, np.float32), **TOL[dtype])
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dr),
                                **TOL[dtype])
+
+
+@pytest.mark.parametrize("rows,cols", [(151936, 896), (21504, 4864),
+                                       (1, 896), (49152, 64), (5, 257),
+                                       (1, 1), (3, 40000), (8, 32769)])
+def test_prox_update_block_fits_its_vmem_budget(rows, cols):
+    """A block, padded as VMEM holds it (rows to 8, the last dim to
+    whole 128-lane tiles), stays within BLOCK_ELEMS, and is a legal TPU
+    block: rows a multiple of 8 or all of them, columns a multiple of
+    128 or all of them."""
+    from repro.kernels.prox_update import BLOCK_ELEMS, _block_shape
+    br, bc = _block_shape(rows, cols)
+    assert br == rows or br % 8 == 0
+    assert bc == cols or bc % 128 == 0
+    assert -(-br // 8) * 8 * (-(-bc // 128) * 128) <= BLOCK_ELEMS
 
 
 @pytest.mark.parametrize("backend,interpret", [("cpu", True),
