@@ -23,16 +23,59 @@ class MoEConfig:
     d_ff_expert: int = 0            # per-expert FFN width
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.01   # load-balance loss weight
+    # expert parallelism: this layer holds routed experts
+    # [expert_offset, expert_offset + experts_held) of num_experts (0 =>
+    # all); the router still scores and picks among all of them
+    experts_held: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = True     # renormalise the top-k gates to sum 1
+    # "switch": over the batch (Switch Transformer); "seq": DeepSeek-V2's
+    # expert balance loss per sequence, averaged (arXiv:2405.04434 2.2.3)
+    aux_kind: str = "switch"
+
+    def __post_init__(self):
+        if self.experts_held == 0:
+            object.__setattr__(self, "experts_held", self.num_experts)
+        if not (0 <= self.expert_offset and self.expert_offset
+                + self.experts_held <= self.num_experts):
+            raise ValueError(f"experts held out of range: {self}")
+        if self.aux_kind not in ("switch", "seq"):
+            raise ValueError(f"unknown aux_kind {self.aux_kind!r}")
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 multi-head latent attention."""
+    """DeepSeek-V2 multi-head latent attention.  q_lora_rank 0 (or None,
+    as DeepSeek-V2-Lite's config.json has it): queries come straight
+    from one projection, with no low-rank query path."""
     kv_lora_rank: int = 512
     q_lora_rank: int = 1536
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+    def __post_init__(self):
+        if self.q_lora_rank is None:
+            object.__setattr__(self, "q_lora_rank", 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071), with the keys of a Hugging
+    Face config's `rope_scaling` group as DeepSeek-V2 writes them.  Only
+    mscale == mscale_all_dim is implemented (DeepSeek-V2's): cos and sin
+    then stay unscaled, and the softmax scale takes mscale^2."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+    def __post_init__(self):
+        if self.type != "yarn" or self.mscale != self.mscale_all_dim:
+            raise NotImplementedError(f"rope scaling {self}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +98,7 @@ class ArchConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[RopeScaling] = None   # MLA only
     attn_window: int = 0            # 0 = full causal; >0 = sliding window
     # sliding-window override used only for the long_500k shape on archs
     # whose native attention is full (see DESIGN.md long-context policy)
@@ -87,6 +131,17 @@ class ArchConfig:
     compute_dtype: str = "bfloat16"
 
     def __post_init__(self):
+        # the nested groups of a JSON config arrive as dicts, lists
+        # where tuples are meant
+        for name, kind in (("moe", MoEConfig), ("mla", MLAConfig),
+                           ("rope_scaling", RopeScaling)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, kind(**getattr(self, name)))
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.rope_scaling is not None and self.mla is None:
+            raise NotImplementedError(
+                f"{self.name}: rope scaling is implemented for MLA only")
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.num_heads)

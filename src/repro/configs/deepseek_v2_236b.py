@@ -2,9 +2,8 @@
 [arXiv:2405.04434]
 
 60L, d_model=5120, 128H (MLA), per-expert d_ff=1536, vocab=102400,
-160 routed experts top-6 + 2 shared. Simplification recorded in DESIGN.md:
-DeepSeek-V2's first dense layer is modeled as MoE like the rest (uniform
-scan); MLA decode uses the absorbed latent formulation.
+160 routed experts top-6 + 2 shared.  Its departures (the first layer
+is modeled as MoE) are listed in the registry, `repro.configs`.
 
 Agent grouping: replicas are far too large for 16 chips — G=8 data indices
 per agent (A=2 single-pod, A=4 multi-pod), M=2 walks, bf16 params.

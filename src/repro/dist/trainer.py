@@ -155,6 +155,9 @@ def make_train_step(model, tcfg):
             metrics = {"loss": jnp.mean(losses),
                        "nll": jnp.mean(metr["nll"]),
                        "aux": jnp.mean(metr["aux"])}
+            if "moe_dropped" in metr:
+                # expert assignments dropped over capacity, all agents
+                metrics["moe_dropped"] = jnp.sum(metr["moe_dropped"])
 
         with jax.named_scope("apibcd.select"):
             rel = jnp.mod(jnp.arange(a) - step, a)

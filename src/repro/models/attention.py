@@ -17,7 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.hints import shard_hint
-from repro.models.layers import _he, apply_rope, rmsnorm, rmsnorm_init
+from repro.models.layers import (
+    _he, apply_rope, rmsnorm, rmsnorm_init, yarn_mscale,
+)
 
 _NEG_INF = -1e30
 
@@ -76,12 +78,13 @@ def _project_qkv(params, cfg, x, positions):
 
 
 def chunked_attention(q, k, v, *, causal=True, window=0,
-                      q_chunk=1024, kv_chunk=1024, q_offset=0):
+                      q_chunk=1024, kv_chunk=1024, q_offset=0, scale=None):
     """Online-softmax attention with O(chunk^2) activation memory.
 
     q: [B, S, KV, G, hd]; k, v: [B, T, KV, hd].
     window > 0 limits attention to the last `window` positions (inclusive).
     q_offset: absolute position of q[0] (for cross-chunk decode/prefill).
+    scale: the softmax scale, 1/sqrt(hd) unless given.
     Returns [B, S, KV, G, hd].
 
     Internally everything is head-major [B, H, s, hd] (k/v broadcast over
@@ -114,7 +117,8 @@ def chunked_attention(q, k, v, *, causal=True, window=0,
         vh = jnp.pad(vh, ((0, 0), (0, 0), (0, t_pad), (0, 0)))
     nq, nkv = (s + s_pad) // q_chunk, (t + t_pad) // kv_chunk
 
-    scale = float(1.0 / np.sqrt(hd))
+    if scale is None:
+        scale = float(1.0 / np.sqrt(hd))
     qh = qh.reshape(b, h, nq, q_chunk, hd)
     kh = kh.reshape(b, h, nkv, kv_chunk, hd)
     vh = vh.reshape(b, h, nkv, kv_chunk, hd_v)
@@ -490,14 +494,20 @@ def gqa_decode_paged(params, cfg, x, cache, tables, lengths, window=0):
 
 
 def mla_init(key, cfg, dtype):
+    """Without a query LoRA (q_lora_rank 0) one `wq` [d, h * qk] takes
+    the place of wq_a, q_norm and wq_b."""
     m = cfg.mla
     d, h = cfg.d_model, cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 7)
+    if m.q_lora_rank:
+        q = {"wq_a": _he(ks[0], (d, m.q_lora_rank), dtype),
+             "q_norm": rmsnorm_init(m.q_lora_rank, dtype),
+             "wq_b": _he(ks[1], (m.q_lora_rank, h * qk), dtype)}
+    else:
+        q = {"wq": _he(ks[0], (d, h * qk), dtype)}
     return {
-        "wq_a": _he(ks[0], (d, m.q_lora_rank), dtype),
-        "q_norm": rmsnorm_init(m.q_lora_rank, dtype),
-        "wq_b": _he(ks[1], (m.q_lora_rank, h * qk), dtype),
+        **q,
         "wkv_a": _he(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_norm": rmsnorm_init(m.kv_lora_rank, dtype),
         "wk_b": _he(ks[3], (m.kv_lora_rank, h * m.qk_nope_head_dim), dtype),
@@ -506,15 +516,33 @@ def mla_init(key, cfg, dtype):
     }
 
 
+def _mla_scale(cfg):
+    """The softmax scale of every MLA path: 1/sqrt(qk head dim), times
+    YaRN's mscale(mscale_all_dim)^2 where the rope is scaled (as
+    DeepSeek-V2's reference code sets it)."""
+    m = cfg.mla
+    scale = float(1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_q_proj(params, x):
+    if "wq" in params:
+        return x @ params["wq"]
+    return rmsnorm(params["q_norm"], x @ params["wq_a"]) @ params["wq_b"]
+
+
 def _mla_q(params, cfg, x, positions):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = rmsnorm(params["q_norm"], x @ params["wq_a"]) @ params["wq_b"]
+    q = _mla_q_proj(params, x)
     q = q.reshape(b, s, h, qk)
     q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_pe
 
 
@@ -523,7 +551,8 @@ def _mla_ckv(params, cfg, x, positions):
     kv = x @ params["wkv_a"]
     c_kv, k_pe = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(params["kv_norm"], c_kv)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0]
     return c_kv, k_pe                              # [B,S,r], [B,S,rope]
 
 
@@ -542,7 +571,7 @@ def mla_prefill(params, cfg, x, positions):
                                   (b, s, h, m.qk_rope_head_dim))], axis=-1)
     # MLA has no KV grouping: kv heads == heads, group g=1
     out = chunked_attention(q[:, :, :, None, :].reshape(
-        b, s, h, 1, q.shape[-1]), k, v, causal=True)
+        b, s, h, 1, q.shape[-1]), k, v, causal=True, scale=_mla_scale(cfg))
     out = out.reshape(b, s, h * m.v_head_dim)
     return out @ params["wo"], (c_kv, k_pe)
 
@@ -572,7 +601,7 @@ def mla_decode(params, cfg, x, cache, position):
     # absorb: q' = q_nope @ wk_b^T  -> [B,H,r]
     q_lat = jnp.einsum("bxhd,rhd->bhr", q_nope.astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scale = float(1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = _mla_scale(cfg)
     logits = (jnp.einsum("bhr,btr->bht", q_lat,
                          ckv.astype(jnp.float32))
               + jnp.einsum("bxhd,btd->bht", q_pe.astype(jnp.float32),
@@ -621,10 +650,9 @@ def mla_prefill_paged(params, cfg, x, cache, table, ctx_len):
     k_ctx, v_ctx = expand(ckv_ctx, kpe_ctx, t)
     k_new, v_new = expand(new_ckv, new_kpe, c)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)        # [1,C,H,qk]
-    qk = q.shape[-1]
     out = _paged_context_attention(
-        q.reshape(b, c, h, 1, qk), k_ctx, v_ctx, k_new, v_new, ctx_len,
-        float(1.0 / np.sqrt(qk)))
+        q.reshape(b, c, h, 1, q.shape[-1]), k_ctx, v_ctx, k_new, v_new,
+        ctx_len, _mla_scale(cfg))
     out = out.reshape(b, c, h * m.v_head_dim).astype(x.dtype)
     new_cache = {
         "ckv": scatter_chunk_pages(cache["ckv"], new_ckv[0], table, ctx_len),
@@ -657,7 +685,7 @@ def mla_decode_paged(params, cfg, x, cache, tables, lengths):
     wk_b = params["wk_b"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bxhd,rhd->bhr", q_nope.astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scale = float(1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = _mla_scale(cfg)
     logits = (jnp.einsum("bhr,btr->bht", q_lat, ckv.astype(jnp.float32))
               + jnp.einsum("bxhd,btd->bht", q_pe.astype(jnp.float32),
                            kpe.astype(jnp.float32))) * scale
@@ -699,7 +727,7 @@ def mla_decode_paged(params, cfg, x, cache, tables, lengths):
 # ---------------------------------------------------------------------------
 
 
-def _rope_mixed(t, nd, pos_d, pos_p, theta):
+def _rope_mixed(t, nd, pos_d, pos_p, theta, scaling=None):
     """apply_rope over the concat token axis, one half at a time.
 
     pos_d [1, nd] / pos_p [1, S] are the halves' own position vectors,
@@ -709,8 +737,9 @@ def _rope_mixed(t, nd, pos_d, pos_p, theta):
     see transformer._mixed_embed).  Rope is elementwise and
     row-stable, so the per-half results are bitwise the concat-rope
     values."""
-    return jnp.concatenate([apply_rope(t[:, :nd], pos_d, theta),
-                            apply_rope(t[:, nd:], pos_p, theta)], axis=1)
+    return jnp.concatenate([apply_rope(t[:, :nd], pos_d, theta, scaling),
+                            apply_rope(t[:, nd:], pos_p, theta, scaling)],
+                           axis=1)
 
 
 def _project_qkv_mixed(params, cfg, x, nd, pos_d, pos_p):
@@ -745,10 +774,11 @@ def _mla_q_mixed(params, cfg, x, nd, pos_d, pos_p):
     b, s, _ = x.shape
     h = cfg.num_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = rmsnorm(params["q_norm"], x @ params["wq_a"]) @ params["wq_b"]
+    q = _mla_q_proj(params, x)
     q = q.reshape(b, s, h, qk)
     q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_pe = _rope_mixed(q_pe, nd, pos_d, pos_p, cfg.rope_theta)
+    q_pe = _rope_mixed(q_pe, nd, pos_d, pos_p, cfg.rope_theta,
+                       cfg.rope_scaling)
     return q_nope, q_pe
 
 
@@ -759,7 +789,7 @@ def _mla_ckv_mixed(params, cfg, x, nd, pos_d, pos_p):
     c_kv, k_pe = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(params["kv_norm"], c_kv)
     k_pe = _rope_mixed(k_pe[:, :, None, :], nd, pos_d, pos_p,
-                       cfg.rope_theta)[:, :, 0]
+                       cfg.rope_theta, cfg.rope_scaling)[:, :, 0]
     return c_kv, k_pe
 
 
@@ -895,7 +925,7 @@ def mla_mixed(params, cfg, x, nd, pos_d, pos_p, cache, p_len, p_slot):
     qd_pe = q_pe[0, :nd][:, None]
     q_lat = jnp.einsum("bxhd,rhd->bhr", qd_nope.astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scale = float(1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = _mla_scale(cfg)
     logits = (jnp.einsum("bhr,btr->bht", q_lat, ckv.astype(jnp.float32))
               + jnp.einsum("bxhd,btd->bht", qd_pe.astype(jnp.float32),
                            kpe.astype(jnp.float32))) * scale
@@ -916,7 +946,8 @@ def mla_mixed(params, cfg, x, nd, pos_d, pos_p, cache, p_len, p_slot):
         [k_nope, jnp.broadcast_to(pp[:, :, None, :],
                                   (1, sp, h, m.qk_rope_head_dim))], axis=-1)
     out_p = chunked_attention(qp[:, :, :, None, :].reshape(
-        1, sp, h, 1, qp.shape[-1]), kp, vp, causal=True)
+        1, sp, h, 1, qp.shape[-1]), kp, vp, causal=True,
+        scale=_mla_scale(cfg))
     out_p = out_p.reshape(1, sp, h * m.v_head_dim)
 
     row_c = prefill_cache_entries(cp, t, sp).astype(ckv.dtype)
@@ -958,7 +989,7 @@ def mla_mixed_paged(params, cfg, x, nd, pos_d, pos_p, cache, tables, lengths,
     qd_pe = q_pe[0, :nd][:, None]
     q_lat = jnp.einsum("bxhd,rhd->bhr", qd_nope.astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scale = float(1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = _mla_scale(cfg)
     logits = (jnp.einsum("bhr,btr->bht", q_lat, ckv.astype(jnp.float32))
               + jnp.einsum("bxhd,btd->bht", qd_pe.astype(jnp.float32),
                            kpe.astype(jnp.float32))) * scale
@@ -991,10 +1022,9 @@ def mla_mixed_paged(params, cfg, x, nd, pos_d, pos_p, cache, tables, lengths,
     k_ctx, v_ctx = expand(ckv_ctx, kpe_ctx, tc)
     k_new, v_new = expand(new_ckv, new_kpe, c)
     qp = jnp.concatenate([q_nope[:, nd:], q_pe[:, nd:]], axis=-1)
-    qk = qp.shape[-1]
     out_p = _paged_context_attention(
-        qp.reshape(1, c, h, 1, qk), k_ctx, v_ctx, k_new, v_new, ctx_len,
-        float(1.0 / np.sqrt(qk)))
+        qp.reshape(1, c, h, 1, qp.shape[-1]), k_ctx, v_ctx, k_new, v_new,
+        ctx_len, _mla_scale(cfg))
     out_p = out_p.reshape(1, c, h * m.v_head_dim).astype(x.dtype)
 
     new_cache = {

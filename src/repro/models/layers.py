@@ -6,6 +6,8 @@ sharding without framework machinery.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -61,15 +63,43 @@ def make_norm(norm_type):
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim, theta):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                       dtype=jnp.float32) / head_dim))
+def rope_frequencies(head_dim, theta, scaling=None):
+    """[head_dim / 2] rotation frequencies; with `scaling` (a YaRN
+    `RopeScaling`), YaRN's blend (arXiv:2309.00071, as DeepSeek-V2's
+    reference code has it): the dims that turn more than beta_fast times
+    over the original context keep theta's frequency, those that turn
+    fewer than beta_slow times take it divided by the factor, and a
+    linear ramp joins the two."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                        dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+
+    def turns_dim(turns):       # the dim that turns `turns` times
+        return (head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (turns * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta=1e4):
-    """x: [..., seq, heads, head_dim]; positions: [..., seq]."""
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature term, 0.1 mscale ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta=1e4, scaling=None):
+    """x: [..., seq, heads, head_dim]; positions: [..., seq].  `scaling`:
+    YaRN's frequencies (`rope_frequencies`); cos and sin stay unscaled,
+    as mscale == mscale_all_dim makes them (`RopeScaling`)."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                       # [hd/2]
+    freqs = rope_frequencies(hd, theta, scaling)              # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., s, hd/2]
     cos = jnp.cos(angles)[..., :, None, :]                     # [..., s, 1, hd/2]
     sin = jnp.sin(angles)[..., :, None, :]

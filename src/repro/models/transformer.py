@@ -92,86 +92,100 @@ def init_cache_layer(cfg, kind, batch, capacity, dtype):
 _ring_insert = ring_insert   # back-compat alias
 
 
+def _attention(cfg, params, h, x, *, positions, mode, cache, window,
+               paged):
+    """x plus the attention of its normed h; returns (x, new_cache)."""
+    if paged is not None:
+        if mode == "prefill":
+            if cfg.mla is not None:
+                attn_out, new_cache = A.mla_prefill_paged(
+                    params["attn"], cfg, h, cache,
+                    paged["table"], paged["ctx_len"])
+            else:
+                attn_out, new_cache = A.gqa_prefill_paged(
+                    params["attn"], cfg, h, cache,
+                    paged["table"], paged["ctx_len"],
+                    window=window, valid=paged.get("valid"))
+        else:
+            if cfg.mla is not None:
+                attn_out, new_cache = A.mla_decode_paged(
+                    params["attn"], cfg, h, cache,
+                    paged["tables"], paged["lengths"])
+            else:
+                attn_out, new_cache = A.gqa_decode_paged(
+                    params["attn"], cfg, h, cache,
+                    paged["tables"], paged["lengths"], window=window)
+        x = x + attn_out
+    elif mode in ("train", "prefill"):
+        if cfg.mla is not None:
+            attn_out, (ckv, kpe) = A.mla_prefill(params["attn"], cfg, h,
+                                                 positions)
+        else:
+            attn_out, (k, v) = A.gqa_prefill(params["attn"], cfg, h,
+                                             positions, window=window)
+        x = x + attn_out
+        new_cache = ()
+        if mode == "prefill":
+            s_len = x.shape[1]
+            ptr = jnp.full((), s_len, jnp.int32)
+            if cfg.mla is not None:
+                t = cache["ckv"].shape[1]
+                new_cache = {
+                    "ckv": prefill_cache_entries(
+                        ckv, t, s_len).astype(cache["ckv"].dtype),
+                    "kpe": prefill_cache_entries(
+                        kpe, t, s_len).astype(cache["kpe"].dtype),
+                    "ptr": ptr}
+            else:
+                t = cache["k"].shape[1]
+                new_cache = {
+                    "k": prefill_cache_entries(
+                        k, t, s_len).astype(cache["k"].dtype),
+                    "v": prefill_cache_entries(
+                        v, t, s_len).astype(cache["v"].dtype),
+                    "ptr": ptr}
+    else:  # decode: insert-then-attend (token attends to itself)
+        pos = positions                         # [B,1] absolute position
+        if cfg.mla is not None:
+            attn_out, new_cache = A.mla_decode(
+                params["attn"], cfg, h, cache, pos)
+        else:
+            attn_out, new_cache = A.gqa_decode(
+                params["attn"], cfg, h, cache, pos, window=window)
+        x = x + attn_out
+
+    return x, new_cache
+
+
 def block_apply(cfg, kind, params, x, *, positions, mode, cache=None,
                 window=0, paged=None):
-    """Returns (x_out, new_cache, aux). aux = scalar (moe load-balance).
+    """Returns (x_out, new_cache, aux): aux {"aux": the moe load-balance
+    loss (0 elsewhere)}, and for moe blocks "moe_dropped", the held
+    experts' assignments dropped over capacity.
 
     paged: None for the arena/linear cache paths; otherwise a dict that
     routes attention through the block-pool variants — for prefill
     {"table": [W], "ctx_len": scalar}, for decode {"tables": [B, W],
     "lengths": [B]} — with `cache` holding the layer's pool leaves."""
     _, norm = make_norm(cfg.norm_type)
-    aux = jnp.zeros((), jnp.float32)
+    aux = {"aux": jnp.zeros((), jnp.float32)}
 
     if kind in ("attn", "moe"):
-        h = norm(params["ln1"], x)
-        if paged is not None:
-            if mode == "prefill":
-                if cfg.mla is not None:
-                    attn_out, new_cache = A.mla_prefill_paged(
-                        params["attn"], cfg, h, cache,
-                        paged["table"], paged["ctx_len"])
-                else:
-                    attn_out, new_cache = A.gqa_prefill_paged(
-                        params["attn"], cfg, h, cache,
-                        paged["table"], paged["ctx_len"],
-                        window=window, valid=paged.get("valid"))
-            else:
-                if cfg.mla is not None:
-                    attn_out, new_cache = A.mla_decode_paged(
-                        params["attn"], cfg, h, cache,
-                        paged["tables"], paged["lengths"])
-                else:
-                    attn_out, new_cache = A.gqa_decode_paged(
-                        params["attn"], cfg, h, cache,
-                        paged["tables"], paged["lengths"], window=window)
-            x = x + attn_out
-        elif mode in ("train", "prefill"):
-            if cfg.mla is not None:
-                attn_out, (ckv, kpe) = A.mla_prefill(params["attn"], cfg, h,
-                                                     positions)
-            else:
-                attn_out, (k, v) = A.gqa_prefill(params["attn"], cfg, h,
-                                                 positions, window=window)
-            x = x + attn_out
-            new_cache = ()
-            if mode == "prefill":
-                s_len = x.shape[1]
-                ptr = jnp.full((), s_len, jnp.int32)
-                if cfg.mla is not None:
-                    t = cache["ckv"].shape[1]
-                    new_cache = {
-                        "ckv": prefill_cache_entries(
-                            ckv, t, s_len).astype(cache["ckv"].dtype),
-                        "kpe": prefill_cache_entries(
-                            kpe, t, s_len).astype(cache["kpe"].dtype),
-                        "ptr": ptr}
-                else:
-                    t = cache["k"].shape[1]
-                    new_cache = {
-                        "k": prefill_cache_entries(
-                            k, t, s_len).astype(cache["k"].dtype),
-                        "v": prefill_cache_entries(
-                            v, t, s_len).astype(cache["v"].dtype),
-                        "ptr": ptr}
-        else:  # decode: insert-then-attend (token attends to itself)
-            pos = positions                         # [B,1] absolute position
-            if cfg.mla is not None:
-                attn_out, new_cache = A.mla_decode(
-                    params["attn"], cfg, h, cache, pos)
-            else:
-                attn_out, new_cache = A.gqa_decode(
-                    params["attn"], cfg, h, cache, pos, window=window)
-            x = x + attn_out
-
+        with jax.named_scope("model.attn"):
+            x, new_cache = _attention(cfg, params, norm(params["ln1"], x),
+                                      x, positions=positions, mode=mode,
+                                      cache=cache, window=window,
+                                      paged=paged)
         h2 = norm(params["ln2"], x)
         if kind == "moe":
             moe_fn = (MOE.moe_apply_scatter
                       if os.environ.get("REPRO_MOE_SCATTER")
                       else MOE.moe_apply)
-            ff, aux = moe_fn(params["moe"], cfg, h2)
+            ff, loss, dropped = moe_fn(params["moe"], cfg, h2)
+            aux = {"aux": loss, "moe_dropped": dropped}
         else:
-            ff = mlp_apply(params["mlp"], h2, cfg.mlp_type)
+            with jax.named_scope("model.mlp"):
+                ff = mlp_apply(params["mlp"], h2, cfg.mlp_type)
         return x + ff, new_cache, aux
 
     if kind == "rwkv":
@@ -274,15 +288,16 @@ def _segment_apply(cfg, kind, seg_params, x, *, positions, mode,
 
     xs = seg_params if seg_cache is None else (seg_params, seg_cache)
     x, (new_caches, auxs) = jax.lax.scan(body, x, xs)
-    return x, new_caches, jnp.sum(auxs)
+    return x, new_caches, jax.tree.map(jnp.sum, auxs)
 
 
 def forward(cfg, params, x, *, positions, mode, caches=None, window=0,
             remat=False, paged=None):
-    """Run the full stack on embeddings x. Returns (x, new_caches, aux)."""
+    """Run the full stack on embeddings x. Returns (x, new_caches, aux),
+    aux the blocks' aux summed over the layers (`block_apply`)."""
     segs = build_segments(cfg.layer_types)
     new_caches = []
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = {"aux": jnp.zeros((), jnp.float32)}
     for si, (kind, count) in enumerate(segs):
         seg_cache = None if caches is None else caches[si]
         x, nc, aux = _segment_apply(cfg, kind, params["segments"][si], x,
@@ -290,7 +305,7 @@ def forward(cfg, params, x, *, positions, mode, caches=None, window=0,
                                     seg_cache=seg_cache, window=window,
                                     remat=remat, paged=paged)
         new_caches.append(nc)
-        aux_total = aux_total + aux
+        aux_total = {k: aux_total.get(k, 0) + v for k, v in aux.items()}
     _, norm = make_norm(cfg.norm_type)
     x = norm(params["final_norm"], x)
     return x, new_caches, aux_total
@@ -320,7 +335,10 @@ def train_loss(cfg, params, batch, window=0, remat=True):
 
     Its phases are named in the compiled program (`jax.named_scope`):
     model.embed, model.blocks (the layer scans) and model.head (logits
-    and loss)."""
+    and loss); inside a block, model.attn, model.mlp and the expert
+    layer's model.moe.* (`repro.models.moe`).  With expert layers the
+    metrics hold "moe_dropped", the held experts' assignments dropped
+    over capacity in this batch."""
     params = _cast(cfg, params)
     tokens = batch["tokens"]
     with jax.named_scope("model.embed"):
@@ -334,8 +352,9 @@ def train_loss(cfg, params, batch, window=0, remat=True):
         b, s, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     with jax.named_scope("model.blocks"):
-        x, _, aux = forward(cfg, params, x, positions=positions,
-                            mode="train", window=window, remat=remat)
+        x, _, auxs = forward(cfg, params, x, positions=positions,
+                             mode="train", window=window, remat=remat)
+    aux = auxs["aux"]
     with jax.named_scope("model.head"):
         x = x[:, n_prefix:]
         logits = logits_fn(cfg, params, x).astype(jnp.float32)
@@ -353,7 +372,10 @@ def train_loss(cfg, params, batch, window=0, remat=True):
         if mask is None:
             mask = jnp.ones_like(nll)
         loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return loss + aux, {"nll": loss, "aux": aux}
+    metrics = {"nll": loss, "aux": aux}
+    if "moe_dropped" in auxs:
+        metrics["moe_dropped"] = auxs["moe_dropped"]
+    return loss + aux, metrics
 
 
 def prefill(cfg, params, batch, window=0, cache_dtype=jnp.bfloat16,

@@ -47,6 +47,24 @@ def test_prox_update(shape, dtype, in_place):
                                **TOL[dtype])
 
 
+# deepseek-v2-lite's leaves as its one-agent superstep holds them (1 dense
+# + 4 MoE layers): the stacked router (64 of 128 lanes), the latent
+# projection wkv_a (576 wide: 4.5 lane tiles) and a routed expert stack
+# (8 held experts a layer, 1408 wide: 11 lane tiles)
+@pytest.mark.parametrize("shape", [(1, 4, 2048, 64), (1, 5, 2048, 576),
+                                   (1, 4, 8, 2048, 1408)])
+def test_prox_update_equals_the_unfused_update_bit_for_bit(shape):
+    rng = np.random.default_rng(1)
+    x, g, z = (jnp.asarray(rng.standard_normal(shape, np.float32))
+               for _ in range(3))
+    args = dict(tau=0.05, rho=20.0, num_walks=1, num_agents=1)
+    xr, dr = jax.jit(lambda *a: ref.prox_update(*a, **args))(x, g, z)
+    xk, dk = jax.jit(lambda *a: ops.prox_update(*a, **args,
+                                                interpret=True))(x, g, z)
+    np.testing.assert_array_equal(np.asarray(xk), np.asarray(xr))
+    np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
+
+
 @pytest.mark.parametrize("rows,cols", [(151936, 896), (21504, 4864),
                                        (1, 896), (49152, 64), (5, 257),
                                        (1, 1), (3, 40000), (8, 32769)])

@@ -87,8 +87,8 @@ def test_gshard_moe_matches_scatter_dispatch():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((2, 16, 64)), jnp.float32)
 
-    out_g, aux_g = MOE.moe_apply(params, cfg, x)
-    out_s, aux_s = MOE.moe_apply_scatter(params, cfg, x)
+    out_g, aux_g, _ = MOE.moe_apply(params, cfg, x)
+    out_s, aux_s, _ = MOE.moe_apply_scatter(params, cfg, x)
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_s),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(float(aux_g), float(aux_s), rtol=1e-5)

@@ -85,11 +85,15 @@ def full_width_step(topo):
 # (21504 rows: its last 2048-row block is ragged); then a leaf whose
 # second-minor dim is off the 8-row tile (leading dim in the grid), one
 # wider than a column block (ragged columns), and one narrower than a
-# lane tile with many rows (rwkv6's stacked w_lora_a: 64 of 128 lanes)
+# lane tile with many rows (rwkv6's stacked w_lora_a: 64 of 128 lanes);
+# then deepseek-v2-lite's stacked router (64 lanes), latent projection
+# wkv_a (576 wide) and routed expert stack (8 held a layer, 1408 wide)
 @pytest.mark.parametrize("shape", [(1, 151936, 896), (1, 24, 896, 4864),
                                    (1, 896), (1, 24, 896, 128),
                                    (3, 5, 257), (2, 40000),
-                                   (1, 24, 2048, 64)])
+                                   (1, 24, 2048, 64), (1, 4, 2048, 64),
+                                   (1, 5, 2048, 576),
+                                   (1, 4, 8, 2048, 1408)])
 def test_prox_update_kernel_compiles(one_chip, shape):
     x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     step = jax.jit(partial(prox_update, tau=0.05, rho=20.0, num_walks=1,
@@ -176,6 +180,28 @@ def test_train_ring_compiles_full_width_per_shard(topo):
         _folded(jax.tree.leaves(run.state["params"]), 1)
     assert not re.search(r"f32\[(\d+,)*1024\]", hlo)
     assert _planned_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_mla_moe_superstep_compiles_at_its_cut_widths(topo):
+    """The deepseek-v2-lite cell's step (1 dense + 4 MoE layers, 8 of 64
+    experts held, 12800 vocabulary rows, published widths; A=1, M=1,
+    8 x 1024 tokens), built from the benchmark's configuration as the
+    harness builds it, plans under 15 GB of the chip, with one update
+    kernel per parameter leaf."""
+    import json
+    from pathlib import Path
+
+    from repro.configs.base import ArchConfig
+
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / "deepseek-v2-lite.json")
+    cfg = ArchConfig(**json.loads(path.read_text())["model"])
+    run = Superstep(cfg, topo.devices[:1], agents=1, walks=1,
+                    batch_per_agent=8, seq=1024, place=False)
+    compiled = run.lower(run.abstract_batch()).compile()
+    calls = _kernel_calls(compiled.as_text())
+    assert len(calls) == len(jax.tree.leaves(run.state["params"]))
+    assert _planned_bytes(compiled) < 15 * 10**9
 
 
 def test_train_superstep_kernel_sits_under_its_phase_scope(topo):
